@@ -14,7 +14,9 @@ Two kinds of rows:
   1 -> 8 simulated host devices (subprocess per mesh shape; jax locks the
   device count at first init). Simulated devices share one CPU, so these
   document step-time behavior of the lowering, not real speedup; they are
-  reported ungated.
+  reported ungated. The children always run on the CPU backend
+  (``JAX_PLATFORMS=cpu``), so they never need the chip a parent process
+  may hold; on a TPU host the rows say so.
 """
 
 from __future__ import annotations
@@ -69,6 +71,7 @@ print("LOSS", float(m["loss"]))
 
 def _timed_row(pods: int, data: int, *, codec, n_sim: int) -> Dict:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"  # simulated devices; never the chip
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_sim}"
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
@@ -85,7 +88,7 @@ def _timed_row(pods: int, data: int, *, codec, n_sim: int) -> Dict:
                       if ln.startswith("LOSS")))
     tag = codec or "off"
     return {"name": f"mesh.step.{pods}x{data}.{tag}", "us_per_call": us,
-            "derived": f"{ROWS} rows on {pods * data} simulated devices "
+            "derived": f"{ROWS} rows on {pods * data} simulated CPU devices "
                        f"codec={tag} loss={loss:.4f}"}
 
 
@@ -154,6 +157,11 @@ def run() -> List[Dict]:
                    f"{flat.dedup_interpod_bytes_flat} B raw flat ids"})
 
     # ---- measured scaling curve, 1 -> 8 simulated devices (ungated)
+    import jax
+    if jax.default_backend() != "cpu":
+        print(f"mesh: the mesh.step.* rows time simulated CPU devices in "
+              f"child processes, not this {jax.default_backend()} backend",
+              file=sys.stderr)
     for pods_, data_ in MESH_SHAPES:
         rows.append(_timed_row(pods_, data_, codec=None, n_sim=8))
     rows.append(_timed_row(2, 4, codec="bf16", n_sim=8))

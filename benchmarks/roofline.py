@@ -24,26 +24,40 @@ import json
 import os
 from typing import Dict, List, Optional
 
-# TPU v5e hardware constants (assignment-specified)
-PEAK_FLOPS = 197e12      # bf16 FLOP/s per chip
-HBM_BW = 819e9           # B/s per chip
-LINK_BW = 50e9           # B/s per ICI link
+# Per-chip peaks keyed by jax ``Device.device_kind``. v5e: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# ICI per chip = 4 links x 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+# The dry-run compiles for v5e meshes; its records carry no device kind.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> Dict[str, float]:
+    """The chip's published peaks; an unknown device is an error, never a
+    default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 
 def analyze_record(r: Dict) -> Optional[Dict]:
     if r.get("status") != "ok":
         return None
+    peak = peaks_for(r.get("device_kind", DRYRUN_DEVICE_KIND))
     n_dev = r["n_devices"]
     flops_dev = r["hlo_flops_per_device"]
     bytes_dev = r["hlo_bytes_per_device"]
     coll_dev = r.get("collective_total_bytes", 0)
-    compute_s = flops_dev / PEAK_FLOPS
-    memory_s = bytes_dev / HBM_BW
-    coll_s = coll_dev / LINK_BW
+    compute_s = flops_dev / peak["flops"]
+    memory_s = bytes_dev / peak["hbm_bw"]
+    coll_s = coll_dev / peak["link_bw"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     dominant = max(terms, key=terms.get)
     model_flops_dev = r.get("model_flops", 0.0) / n_dev
-    useful_s = model_flops_dev / PEAK_FLOPS
+    useful_s = model_flops_dev / peak["flops"]
     bound_s = max(terms.values())
     return {
         "arch": r["arch"], "shape": r["shape"], "mesh": r["mesh"],

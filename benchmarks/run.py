@@ -144,6 +144,8 @@ def main() -> None:
                          "exit nonzero if a gated row regressed >25%")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import bench_devicefeed, bench_end_to_end, \
         bench_feature_extraction, bench_hierarchy, bench_ingest, \
         bench_launch_overhead, bench_mesh, bench_pipeline, \

@@ -1,14 +1,5 @@
 """FeatureBox reproduction: GPU feature engineering + pipelined training.
 
-Importing any ``repro.*`` module installs the JAX compat shims (see
-:mod:`repro.compat`) so code written against newer JAX sharding APIs runs on
-the pinned version as well. Subpackages without an ``__init__`` (``launch``,
-``models``, ``train``, ...) remain importable as namespace portions.
+Subpackages without an ``__init__`` (``launch``, ``models``, ``train``, ...)
+remain importable as namespace portions.
 """
-
-from repro import compat as _compat
-
-# Install only if jax is already imported: keeps `import repro.io` (the
-# numpy-only ingest tier) jax-free. Modules that consume the patched APIs
-# (launch.mesh, models.moe, models.gnn, embedding.dedup) install eagerly.
-_compat.install(require_jax=False)

@@ -93,6 +93,14 @@ def run_check(preset: str, arch: str, *, rows: int = 8,
                                                        location=where)
                 findings += aliasing.check_ring(layout, rows, buffers=3,
                                                 location=where)
+            if not any(f.rule == "AL204" for f in findings):
+                from repro.kernels.mempool_alloc.ops import kernel_interpreted
+                mode = ("interpreted" if kernel_interpreted()
+                        else "compiled for the TPU")
+                findings.append(Finding(
+                    rule="AL204", severity="info", location=preset,
+                    message=(f"planners {', '.join(aliasing.PLANNERS)} agree "
+                             f"on both feed layouts; pallas_kernel {mode}")))
             report.record_analyzer("aliasing", findings)
         except Exception as e:  # noqa: BLE001
             report.record_crash("aliasing", e)

@@ -45,8 +45,11 @@ import numpy as np
 
 from repro.check.findings import Finding
 from repro.core.mempool import ALIGN, ArenaPool, align_up
+from repro.kernels.mempool_alloc.ops import plan_block
 
 _I32_MAX = np.iinfo(np.int32).max
+# The oracles AL204 compares, as :func:`check_feed_layout` names them.
+PLANNERS = ("arena_pool", "pallas_kernel", "plan_offsets", "shadow")
 
 
 def _shadow_plan(sizes: Sequence[int], align: int) -> Tuple[List[int], int]:
@@ -169,16 +172,12 @@ def check_feed_layout(layout, rows: int, *,
         # finding already reports the hazard.
         return findings
 
-    plans: Dict[str, Tuple[Sequence[int], int]] = {
+    plans: Dict[str, Tuple[Sequence[int], int]] = {  # keys: PLANNERS
         "shadow": (shadow_offsets, shadow_total)}
     offsets, total = layout.plan(rows)
     plans["plan_offsets"] = (list(np.asarray(offsets)), int(total))
-    try:
-        from repro.kernels.mempool_alloc.ops import plan_block
-        k_offsets, k_total = plan_block(sizes, align=align)
-        plans["pallas_kernel"] = (list(np.asarray(k_offsets)), int(k_total))
-    except ImportError:  # kernel path absent on this install: skip oracle
-        pass
+    k_offsets, k_total = plan_block(sizes, align=align)
+    plans["pallas_kernel"] = (list(np.asarray(k_offsets)), int(k_total))
     pool = ArenaPool(shadow_total, align=align)
     allocs = pool.alloc_block(sizes)
     plans["arena_pool"] = ([a.offset for a in allocs], shadow_total)

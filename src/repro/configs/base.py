@@ -47,8 +47,10 @@ class ArchSpec:
     family: str                        # "lm" | "recsys" | "gnn"
     shapes: Tuple[str, ...]
     build_cell: Callable[..., Cell]    # (shape, mesh, dp, variant) -> Cell
-    smoke: Callable[[], Any]           # returns (config, batch_builder)
+    smoke: Callable[[], Any]           # reduced config for CPU runs and tests
     describe: str = ""
+    # the published config (``--full``); None where it differs per shape
+    config: Any = None
 
 
 def _shard_tree(mesh: Mesh, spec_tree):

@@ -32,5 +32,6 @@ ARCH = ArchSpec(
     shapes=("train_batch", "serve_p99", "serve_bulk", "retrieval_cand"),
     build_cell=functools.partial(recsys_cell, CONFIG),
     smoke=smoke,
+    config=CONFIG,
     describe="Behavior Sequence Transformer over user click history",
 )

@@ -44,5 +44,6 @@ ARCH = ArchSpec(
     shapes=("train_4k", "prefill_32k", "decode_32k", "long_500k"),
     build_cell=functools.partial(lm_cell, CONFIG),
     smoke=smoke,
+    config=CONFIG,
     describe="MLA + fine-grained MoE (2 shared + 160 routed top-6)",
 )

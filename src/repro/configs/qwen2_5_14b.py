@@ -27,5 +27,6 @@ ARCH = ArchSpec(
     shapes=("train_4k", "prefill_32k", "decode_32k", "long_500k"),
     build_cell=functools.partial(lm_cell, CONFIG),
     smoke=smoke,
+    config=CONFIG,
     describe="GQA dense transformer with QKV bias",
 )

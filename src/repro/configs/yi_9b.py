@@ -28,5 +28,6 @@ ARCH = ArchSpec(
     shapes=("train_4k", "prefill_32k", "decode_32k", "long_500k"),
     build_cell=functools.partial(lm_cell, CONFIG),
     smoke=smoke,
+    config=CONFIG,
     describe="llama-arch GQA dense transformer",
 )

@@ -17,10 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
-
-compat.install()  # jax.shard_map on older jax
-
 # Sentinel for unused working-set slots (never a valid row id).
 FILL = jnp.int32(2**31 - 1)
 

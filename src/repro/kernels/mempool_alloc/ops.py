@@ -1,7 +1,7 @@
 """Jitted public wrapper for the mempool allocator kernel.
 
 Selects the Pallas kernel on TPU (compiled) and interpret mode elsewhere;
-falls back to the jnp reference for shapes the kernel doesn't support.
+an empty block takes the jnp reference.
 """
 
 from __future__ import annotations
@@ -30,8 +30,13 @@ def plan_allocation(sizes: jax.Array, *, align: int = ALIGN, use_kernel: bool = 
         raise ValueError(f"sizes must be rank-1, got {sizes.shape}")
     if sizes.shape[0] == 0 or not use_kernel:
         return alloc_offsets_ref(sizes, align=align)
-    interpret = jax.default_backend() != "tpu"
-    return alloc_offsets(sizes, align=align, interpret=interpret)
+    return alloc_offsets(sizes, align=align, interpret=kernel_interpreted())
+
+
+def kernel_interpreted() -> bool:
+    """Whether the kernel runs in interpret mode: everywhere but on a TPU,
+    where Mosaic compiles it."""
+    return jax.default_backend() != "tpu"
 
 
 def plan_block(sizes: Sequence[int], *, align: int = ALIGN,

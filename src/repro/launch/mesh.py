@@ -9,10 +9,6 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
-
-compat.install()  # axis_types= / AxisType on older jax
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""

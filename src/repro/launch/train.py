@@ -1,10 +1,12 @@
 """End-to-end training driver: ``--arch <id>`` selects any assigned config.
 
-On this CPU container it runs the REDUCED (smoke) config of the chosen
-architecture with synthetic data through the full production path: FeatureBox
-FE pipeline (recsys archs), jitted train step, async checkpointing, restart.
-On a real TPU cluster the same driver runs the full config by passing
-``--full`` (the step functions and shardings are the dry-run-validated ones).
+By default it builds the arch's reduced (smoke) config, which runs on a CPU
+in seconds; ``--full`` builds the published config instead (``dcn-v2``'s
+fits one 16 GB chip; ``dlrm-mlperf``'s does not). Either config runs on
+whatever backend JAX finds, through the same path: the FeatureBox FE
+pipeline (recsys archs), the jitted train step, async checkpointing,
+restart. ``main(argv)`` returns the run's summary (losses, steps, runner
+stats), and a non-finite loss ends the run with an error.
 
 Two batch sources:
 
@@ -28,6 +30,7 @@ from __future__ import annotations
 
 import argparse
 import itertools
+import math
 import time
 from typing import Any, Dict
 
@@ -71,7 +74,7 @@ def synthetic_batch(family: str, cfg, batch: int, step: int) -> Dict[str, Any]:
 from repro.fe.modelfeed import fe_env_to_model_batch_ref as fe_env_to_model_batch  # noqa: E402,E501
 
 
-def run_streaming(args, spec, cfg, state, opt, check_report=None) -> None:
+def run_streaming(args, spec, cfg, key, opt, check_report=None) -> Dict[str, Any]:
     """Stream raw-log shards from disk through FE into the train step.
 
     The stage->train boundary is compiled: ``repro.fe.modelfeed`` derives
@@ -84,7 +87,9 @@ def run_streaming(args, spec, cfg, state, opt, check_report=None) -> None:
     ``--device-feed arena`` stages per-field id vectors straight into the
     ring arena (``split_sparse_fields``), and the staged batch + params +
     optimizer state are donated through the jit (``--no-donate`` opts out)
-    with the feeder's ``donation_fence`` accounting the reuse.
+    with the feeder's ``donation_fence`` accounting the reuse. The train
+    state is created by the chosen step's init, in one jit and in its final
+    placement (row-sharded on a mesh). Returns the run summary.
     """
     import dataclasses
     import os
@@ -190,7 +195,12 @@ def run_streaming(args, spec, cfg, state, opt, check_report=None) -> None:
                 "--embedding hierarchy needs a tuned working-set capacity "
                 "and the dataset manifest has no rows hint — regenerate the "
                 "shards (repro.fe.datagen writes the manifest)")
-        raw_step, _, _ = R.make_hierarchy_train_step(cfg, opt)
+        raw_step, init_opt, _ = R.make_hierarchy_train_step(cfg, opt)
+        # Embedding rows live in the PS file, not in params: dense tree
+        # only (same fold_in enumeration, so dense init is bitwise
+        # identical to the in-memory backend).
+        params, opt_state = R.init_train_state(cfg, key, init_opt,
+                                               include_embed=False)
         extra_slots = WS_SLOTS
     elif mesh is not None:
         # Data-parallel scale-out: table rows + Adagrad accumulators
@@ -209,12 +219,9 @@ def run_streaming(args, spec, cfg, state, opt, check_report=None) -> None:
         raw_step, mesh_init, _ = R.make_mesh_train_step(
             cfg, opt, mesh=mesh, compress=args.compress,
             local_dedup_capacity=local_cap)
-        # Rebuild + place the train state per the sharding contract: the
-        # generic init in _run lacks the codec's error-feedback residual
-        # and the NamedSharding placements.
-        state["opt"] = mesh_init(state["params"])
-        state["params"], state["opt"] = R.shard_train_state(
-            mesh, state["params"], state["opt"])
+        # Born row-sharded: no device holds the whole table at any point.
+        params, opt_state = R.init_train_state(cfg, key, mesh_init,
+                                               mesh=mesh)
         rows_dev = max(1, (loader.rows_hint or args.batch) // n_mesh_dev)
         ids_dev = R.batch_id_count(cfg, rows_dev)
         comm = CommStats(plan=CommPlan.for_step(
@@ -228,8 +235,11 @@ def run_streaming(args, spec, cfg, state, opt, check_report=None) -> None:
         print(f"comm plan: {comm.summary()}")
         extra_slots = ()
     else:
-        raw_step, _, _ = R.make_sparse_train_step(cfg, opt)
+        raw_step, init_opt, _ = R.make_sparse_train_step(cfg, opt)
+        params, opt_state = R.init_train_state(cfg, key, init_opt)
         extra_slots = ()
+    state = {"params": params, "opt": opt_state}
+    del params, opt_state  # donated by the first step
 
     # Restart-from-latest, possibly across a remesh: the checkpoint holds
     # host arrays (topology-free), so restoring into the *current* state
@@ -305,6 +315,7 @@ def run_streaming(args, spec, cfg, state, opt, check_report=None) -> None:
         print(line)
 
     losses = []
+    step_seconds = []  # host clock per step, first one includes compile
     cost_args = []  # (params, opt, feed) ShapeDtypeStructs for --metrics
     from repro.obs.trace import get_tracer
     tracer = get_tracer()
@@ -322,12 +333,17 @@ def run_streaming(args, spec, cfg, state, opt, check_report=None) -> None:
             p, o = abstractify((state["params"], state["opt"]))
             cost_args.append((p, o, feed))
         w0 = tracer.now_ns() if (tracer.enabled and comm is not None) else 0
+        t0 = time.perf_counter()
         p, o, m = fused(state["params"], state["opt"], env)
         if hier is not None:
             # Async write-back: hand the updated working set to the PS
             # writer thread; the pull for batch i+2 waits on it, not us.
             hier.complete(env[WS_META], m.pop("ws_rows"), m.pop("ws_accum"))
         losses.append(float(m["loss"]))  # blocks until the step lands
+        step_seconds.append(time.perf_counter() - t0)
+        if not math.isfinite(losses[-1]):
+            raise FloatingPointError(
+                f"non-finite loss {losses[-1]} at step {len(losses) - 1}")
         if comm is not None:
             comm.on_step()
             if tracer.enabled:
@@ -360,7 +376,7 @@ def run_streaming(args, spec, cfg, state, opt, check_report=None) -> None:
     shard_iter = iter(loader)  # kept so the generator can be closed below
     t0 = time.perf_counter()
     try:
-        runner.run(state, itertools.islice(shard_iter, args.steps))
+        state = runner.run(state, itertools.islice(shard_iter, args.steps))
     finally:
         # Close the generator explicitly (its finally finalizes the
         # loader's wall-clock stats) before stopping the reader pool —
@@ -417,6 +433,19 @@ def run_streaming(args, spec, cfg, state, opt, check_report=None) -> None:
             _print_hlo_cost(tot)
         print("metrics:")
         print(reg.to_json())
+    return {"arch": args.arch, "config": cfg, "steps": s.batches,
+            "losses": losses, "step_seconds": step_seconds, "stats": s,
+            "table_shards": _table_shards(state["params"])}
+
+
+def _table_shards(params) -> Any:
+    """(platform, device id, rows) of each addressable shard of the
+    embedding table, or None where the table lives off-device (PS)."""
+    embed = params.get("embed")
+    if embed is None:
+        return None
+    return [(sh.device.platform, sh.device.id, int(sh.data.shape[0]))
+            for sh in embed.addressable_shards]
 
 
 def _print_hlo_cost(tot) -> None:
@@ -428,9 +457,14 @@ def _print_hlo_cost(tot) -> None:
           f"intensity={tot.flops/max(tot.bytes, 1.0):.2f} flop/byte")
 
 
-def main() -> None:
+def main(argv=None) -> Dict[str, Any]:
+    """Parse ``argv`` (default ``sys.argv[1:]``), train, and return the run
+    summary: ``losses``, ``steps``, runner ``stats`` and the ``config``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_archs())
+    ap.add_argument("--full", action="store_true",
+                    help="build the arch's published config instead of its "
+                         "reduced smoke config")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=64)
     ap.add_argument("--lr", type=float, default=1e-3)
@@ -549,13 +583,15 @@ def main() -> None:
                          "snapshot (JSON) plus per-step HLO FLOPs / "
                          "HBM-bytes at exit (the HLO analysis costs one "
                          "extra compile)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.trace:
         from repro.obs.trace import enable_tracing
         enable_tracing()
     try:
-        _run(args)
+        return _run(args)
     finally:
         if args.trace:
             from repro.obs.trace import get_tracer
@@ -585,9 +621,12 @@ def _preflight(args, spec):
     return report
 
 
-def _run(args) -> None:
+def _run(args) -> Dict[str, Any]:
     spec = get_arch(args.arch)
-    cfg = spec.smoke()
+    if args.full and spec.config is None:
+        raise SystemExit(f"{args.arch} has no single published config "
+                         f"(it differs per shape)")
+    cfg = spec.config if args.full else spec.smoke()
     if args.vocab_scale != 1.0:
         if spec.family != "recsys":
             raise SystemExit("--vocab-scale only applies to recsys archs")
@@ -630,6 +669,12 @@ def _run(args) -> None:
     key = jax.random.PRNGKey(0)
     opt = adamw(args.lr)
     check_report = _preflight(args, spec) if args.check else None
+    if args.data_dir:
+        # The streaming path builds its own boundary step: the working-set
+        # capacity is tuned from the dataset manifest, and the step (table,
+        # mesh or PS) decides the state's placement.
+        return run_streaming(args, spec, cfg, key, opt,
+                             check_report=check_report)
 
     if spec.family == "lm":
         from repro.models import transformer as T
@@ -638,19 +683,9 @@ def _run(args) -> None:
         opt_state = opt.init(params)
     elif spec.family == "recsys":
         from repro.models import recsys as R
-        if args.embedding == "hierarchy":
-            # Embedding rows live in the PS file, not in params: dense tree
-            # only (same fold_in enumeration, so dense init is bitwise
-            # identical to the in-memory backend); the hierarchy train step
-            # is compiled in run_streaming with the data-tuned capacity.
-            params = R.init_params(cfg, key, include_embed=False)
-            train_step = None
-            opt_state = {"dense": opt.init(params)}
-        else:
-            params = R.init_params(cfg, key)
-            step_fn, init_st, _ = R.make_sparse_train_step(cfg, opt)
-            train_step = jax.jit(step_fn)
-            opt_state = init_st(params)
+        step_fn, init_st, _ = R.make_sparse_train_step(cfg, opt)
+        train_step = jax.jit(step_fn)
+        params, opt_state = R.init_train_state(cfg, key, init_st)
     else:
         from repro.models import gnn as G
         params = G.init_params(cfg, key)
@@ -658,14 +693,6 @@ def _run(args) -> None:
         opt_state = opt.init(params)
 
     state = {"params": params, "opt": opt_state}
-
-    if args.data_dir:
-        # The streaming path builds its own boundary step: the working-set
-        # capacity is tuned from the dataset manifest, so the train step
-        # is compiled there (same state/optimizer structure).
-        run_streaming(args, spec, cfg, state, opt,
-                      check_report=check_report)
-        return
 
     def step_wrapper(state, batch):
         p, o, m = train_step(state["params"], state["opt"], batch)
@@ -700,7 +727,11 @@ def _run(args) -> None:
         _print_hlo_cost(tot)
         print("metrics:")
         print(reg.to_json())
+    if not all(math.isfinite(v) for v in stats.losses):
+        raise FloatingPointError(f"non-finite loss in {stats.losses}")
     assert stats.losses[-1] < stats.losses[0], "training must reduce loss"
+    return {"arch": args.arch, "config": cfg, "steps": stats.steps,
+            "losses": list(stats.losses), "stats": stats}
 
 
 if __name__ == "__main__":

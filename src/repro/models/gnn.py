@@ -28,10 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
-
-compat.install()  # jax.shard_map on older jax
-
 from repro.models.common import he_init, softmax_xent
 
 Params = Dict[str, Any]

@@ -24,11 +24,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from repro import compat
-
-compat.install()  # jax.shard_map on older jax
-
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import dense

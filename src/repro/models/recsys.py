@@ -712,29 +712,56 @@ def make_mesh_train_step(c: RecsysConfig, dense_optimizer, *,
     return train_step, init, abstract_state
 
 
-def shard_train_state(mesh, params: Params, opt_state: Dict[str, Any], *,
-                      pod_axis: str = "pod", data_axis: str = "data"):
-    """Place (params, opt_state) per the mesh step's sharding contract:
-    embedding rows + Adagrad accumulators split over the flattened mesh,
-    the dense tree replicated, the codec residual (when present) split so
-    each device owns its reduce-scattered shard."""
+def train_state_shardings(mesh, params: Params, opt_state: Dict[str, Any], *,
+                          pod_axis: str = "pod", data_axis: str = "data"):
+    """The mesh step's sharding contract for (params, opt_state): embedding
+    rows + Adagrad accumulators split over the flattened mesh, the dense
+    tree replicated, the codec residual (when present) split so each device
+    owns its reduce-scattered shard. Leaves may be arrays or shapes."""
     from jax.sharding import NamedSharding
 
     axes = (pod_axis, data_axis)
 
-    def put(x, spec):
-        return jax.device_put(x, NamedSharding(mesh, spec))
+    def sh(spec):
+        return NamedSharding(mesh, spec)
 
-    new_params = {k: put(v, P(axes, None) if k == "embed" else P())
-                  for k, v in params.items()}
-    new_opt: Dict[str, Any] = {
-        "dense": jax.tree.map(lambda v: put(v, P()), opt_state["dense"]),
-        "embed_accum": put(opt_state["embed_accum"], P(axes)),
+    p_sh = {k: sh(P(axes, None) if k == "embed" else P()) for k in params}
+    o_sh: Dict[str, Any] = {
+        "dense": jax.tree.map(lambda _: sh(P()), opt_state["dense"]),
+        "embed_accum": sh(P(axes)),
     }
     if "comm_residual" in opt_state:
-        new_opt["comm_residual"] = put(opt_state["comm_residual"],
-                                       P(pod_axis, data_axis))
-    return new_params, new_opt
+        o_sh["comm_residual"] = sh(P(pod_axis, data_axis))
+    return p_sh, o_sh
+
+
+def shard_train_state(mesh, params: Params, opt_state: Dict[str, Any], **kw):
+    """Place existing (params, opt_state) per :func:`train_state_shardings`
+    (the resume path: restored host arrays onto the current mesh)."""
+    return jax.device_put((params, opt_state),
+                          train_state_shardings(mesh, params, opt_state, **kw))
+
+
+def init_train_state(c: RecsysConfig, key: jax.Array, init_opt: Callable, *,
+                     mesh=None, include_embed: bool = True):
+    """Materialize ``(params, init_opt(params))`` in one jit, born in place.
+
+    One program keeps only its outputs and small temporaries live; eager
+    init would dispatch op by op, and at published widths the table's
+    random bits and their float copy would both sit on one device. With
+    ``mesh`` the outputs are created in the :func:`train_state_shardings`
+    layout, so no device ever holds the whole table. Threefry is
+    partitionable, so the values equal the single-device init's.
+    """
+    def _init(k):
+        params = init_params(c, k, include_embed=include_embed)
+        return params, init_opt(params)
+
+    if mesh is None:
+        return jax.jit(_init)(key)
+    shapes = jax.eval_shape(_init, key)
+    return jax.jit(_init, out_shardings=train_state_shardings(
+        mesh, *shapes))(key)
 
 
 def gid_site_shapes(c: RecsysConfig, batch: Dict[str, Any]) -> Dict[str, Tuple[int, ...]]:

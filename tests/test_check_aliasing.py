@@ -138,3 +138,21 @@ def test_corrupt_plan_offsets_detected_against_oracle():
     findings = check_plan(layout.sizes(16), list(bad), total,
                           names=layout.slot_names)
     assert "AL201" in _rules(findings)
+
+
+def test_feed_layout_consults_every_planner(monkeypatch):
+    """AL204 compares all of PLANNERS, the compiled-or-interpreted Pallas
+    kernel among them (no planner is skipped silently)."""
+    from repro.check import aliasing
+
+    seen = {}
+    real = aliasing.check_agreement
+
+    def spy(plans, **kw):
+        seen.update(plans)
+        return real(plans, **kw)
+
+    monkeypatch.setattr(aliasing, "check_agreement", spy)
+    layout = FeedLayout((SlotSpec("a", 3, "float32"), SlotSpec("b", 1, "int32", rank1=True)))
+    assert check_feed_layout(layout, rows=64) == []
+    assert sorted(seen) == sorted(aliasing.PLANNERS)

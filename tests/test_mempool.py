@@ -50,6 +50,23 @@ def test_kernel_matches_ref_and_pool(sizes):
     assert [a.offset for a in allocs] == np.asarray(off_k).tolist()
 
 
+@pytest.mark.parametrize("n,high", [(1024, 5000), (2500, 5000),
+                                    (3000, 2**21)])
+def test_kernel_matches_ref_across_blocks(n, high):
+    """Several (8, 128) tiles with a partial tail: the bump pointer is
+    carried from block to block, and int32 wrap-around (the last case's
+    total passes 2**31) matches ``jnp.cumsum`` bitwise."""
+    sizes = np.random.default_rng(n).integers(0, high, n).astype(np.int32)
+    off_k, head_k = plan_allocation(jnp.asarray(sizes))
+    off_r, head_r = alloc_offsets_ref(jnp.asarray(sizes))
+    assert (np.asarray(off_k) == np.asarray(off_r)).all()
+    assert int(head_k[0]) == int(head_r[0])
+    if high * n < 2**31:
+        pool = ArenaPool(capacity=align_up(int(head_k[0])))
+        assert [a.offset for a in pool.alloc_block(sizes.tolist())] == \
+            np.asarray(off_k).tolist()
+
+
 def test_exhaustion_raises():
     pool = ArenaPool(capacity=ALIGN * 2)
     with pytest.raises(MemoryError):

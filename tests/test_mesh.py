@@ -188,7 +188,6 @@ def test_two_stage_dedup_matches_flat_dedup():
     run_sub("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro import compat; compat.install()
 from repro.embedding.dedup import FILL, MAX_ID, dedup, dedup_two_stage_local
 from repro.launch.mesh import make_train_mesh
 
@@ -386,7 +385,6 @@ def test_hierarchical_psum_error_feedback_converges():
     run_sub("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro import compat; compat.install()
 from repro.train.compression import flat_psum, hierarchical_psum
 from repro.launch.mesh import make_train_mesh
 
