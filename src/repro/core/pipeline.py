@@ -33,7 +33,9 @@ import os
 import queue
 import threading
 import time
-from typing import Any, Callable, Dict, Iterable, Iterator, List, Mapping, Optional
+from array import array
+from typing import (Any, Callable, Dict, Iterable, Iterator, List, Mapping,
+                    Optional, Sequence)
 
 import numpy as np
 
@@ -60,6 +62,10 @@ class PipelineStats:
     # small overhead instead of misreading the gap as overhead.
     drain_seconds: float = 0.0
     wall_seconds: float = 0.0
+    # Stage seconds hidden by pipelining: the time the FE worker and the
+    # train loop were both busy, from each stage's own per-batch busy
+    # intervals (PipelinedRunner; 0 for the serial StagedRunner).
+    overlap_seconds: float = 0.0
     intermediate_bytes: int = 0  # bytes written to disk between stages
     exec_stats: ExecutionStats = dataclasses.field(default_factory=ExecutionStats)
     # When the batch source is a repro.io.StreamingLoader, its IngestStats
@@ -97,11 +103,14 @@ class PipelineStats:
         return max(self.train_seconds - self.adapt_seconds, 0.0)
 
     # ------------------------------------------------- derived accounting
-    # The accounting identity both runners satisfy (asserted in
+    # The accounting both runners satisfy (asserted in
     # tests/test_pipeline.py):
     #     wall <= fe + train_net + adapt + drain + overhead
-    # with equality for the serial (Staged) runner, overhead >= 0 always,
-    # and the pipelined runner's surplus busy time showing up as overlap.
+    # with overhead >= 0 always, overlap == 0 for the serial (Staged)
+    # runner, and, where only FE and train ran,
+    #     wall == busy - overlap + overhead
+    # Start-up, queue waits and teardown are overhead; the time two stages
+    # shared is overlap, measured, not inferred from wall.
 
     @property
     def busy_seconds(self) -> float:
@@ -114,14 +123,10 @@ class PipelineStats:
     @property
     def overhead_seconds(self) -> float:
         """Wall time no stage accounts for (queue waits, thread startup,
-        end-of-stream drain). Never negative: when stages overlap, busy
-        time can exceed wall and the residual is overlap, not overhead."""
-        return max(self.wall_seconds - self.busy_seconds, 0.0)
-
-    @property
-    def overlap_seconds(self) -> float:
-        """Stage seconds hidden by pipelining (busy time beyond wall)."""
-        return max(self.busy_seconds - self.wall_seconds, 0.0)
+        end-of-stream drain): wall beyond the stages' busy time once the
+        overlap is counted once. Never negative."""
+        return max(self.wall_seconds - self.busy_seconds
+                   + self.overlap_seconds, 0.0)
 
     @property
     def overlap_fraction(self) -> float:
@@ -139,6 +144,21 @@ class PipelineStats:
         :class:`repro.obs.MetricsRegistry`; nested tiers register
         themselves separately."""
         return harvest(self)
+
+
+def _shared_seconds(a: Sequence[float], b: Sequence[float]) -> float:
+    """Seconds two stages were busy at once. Each argument holds one
+    stage's busy intervals as flat ``start, end, start, end, ...`` stamps,
+    in order and disjoint (one thread's per-batch work)."""
+    i = j = 0
+    total = 0.0
+    while i < len(a) and j < len(b):
+        total += max(0.0, min(a[i + 1], b[j + 1]) - max(a[i], b[j]))
+        if a[i + 1] < b[j + 1]:
+            i += 2
+        else:
+            j += 2
+    return total
 
 
 def _capture_ingest(stats: PipelineStats, batches: Any) -> None:
@@ -192,7 +212,8 @@ def _capture_comm(stats: PipelineStats, train_step: Any) -> None:
 @single_writer("stats.fe_seconds",                       # fe-worker thread
                "stats.ps_seconds",                       # ps-feeder thread
                "stats.train_seconds", "stats.batches",   # main train loop
-               "stats.wall_seconds", "stats.feed", "stats.ps")
+               "stats.wall_seconds", "stats.overlap_seconds",
+               "stats.feed", "stats.ps")
 class PipelinedRunner:
     """FeatureBox: FE for batch i+1 overlaps training on batch i.
 
@@ -267,7 +288,8 @@ class PipelinedRunner:
             f"feed must be 'off', 'stage', or 'arena', got {feed!r}")
 
     def _fe_worker(self, batches: Iterator[Mapping[str, Any]],
-                   q: "queue.Queue", stop: threading.Event) -> None:
+                   q: "queue.Queue", stop: threading.Event,
+                   busy: array) -> None:
         tracer = get_tracer()
         try:
             for bi, raw in enumerate(batches):
@@ -278,7 +300,9 @@ class PipelinedRunner:
                     env = dict(raw)
                     run_layers(self.layers, env, device=self.device,
                                stats=self.stats.exec_stats)
-                self.stats.fe_seconds += time.perf_counter() - t0
+                t1 = time.perf_counter()
+                self.stats.fe_seconds += t1 - t0
+                busy.extend((t0, t1))
                 self._put(q, env, stop)
         except BaseException as e:  # surface worker failures to the consumer
             tracer.instant("fe.error", kind=type(e).__name__)
@@ -356,9 +380,12 @@ class PipelinedRunner:
     def run(self, state: Any, batches: Iterable[Mapping[str, Any]]) -> Any:
         q: "queue.Queue" = queue.Queue(maxsize=self.prefetch)
         stop = threading.Event()
+        # Each stage's own busy intervals, written by that stage's thread
+        # alone and intersected after the join.
+        fe_busy, train_busy = array("d"), array("d")
         t_start = time.perf_counter()
         worker = threading.Thread(
-            target=self._fe_worker, args=(iter(batches), q, stop),
+            target=self._fe_worker, args=(iter(batches), q, stop, fe_busy),
             daemon=True, name="fe-worker",
         )
         threads = [worker]
@@ -412,7 +439,9 @@ class PipelinedRunner:
                 t0 = time.perf_counter()
                 with tracer.span("train.step", batch=self.stats.batches):
                     state = self.train_step(state, item)
-                self.stats.train_seconds += time.perf_counter() - t0
+                t1 = time.perf_counter()
+                self.stats.train_seconds += t1 - t0
+                train_busy.extend((t0, t1))
                 self.stats.batches += 1
                 # Release the env before blocking on the next get so batch
                 # memory is reclaimed as soon as the device is done with it.
@@ -446,6 +475,7 @@ class PipelinedRunner:
             if self.ps_feed is not None and hasattr(self.ps_feed, "as_metrics"):
                 self.stats.ps = self.ps_feed
             self.stats.wall_seconds = time.perf_counter() - t_start
+            self.stats.overlap_seconds = _shared_seconds(fe_busy, train_busy)
             _capture_ingest(self.stats, batches)
             _capture_fault(self.stats, batches)
             _capture_train_feed(self.stats, self.train_step)

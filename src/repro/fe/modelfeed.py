@@ -50,6 +50,7 @@ import numpy as np
 
 from repro.embedding.dedup import expected_unique
 from repro.fe.compiler import OutputLayout, field_slot, field_slots
+from repro.obs import phases
 from repro.obs.metrics import harvest
 from repro.obs.trace import get_tracer
 
@@ -342,12 +343,20 @@ class ModelFeed:
         The returned callable carries ``feed_stats`` (this plan's
         :class:`TrainFeedStats`), which the pipeline runners adopt into
         ``PipelineStats.train_feed``.
+
+        Under an enabled tracer each call records ``train.adapt`` (host
+        feed selection), ``train.dispatch`` (the jit call) and
+        ``train.sync`` (:meth:`_record`'s blocking readback) spans. Before
+        its first call under a given enabled tracer, the step records on it
+        which compiled instruction runs in which device phase
+        (:func:`repro.obs.phases.phase_map`, annotation ``step.phases``).
         """
         donate_args = (0, 1, 2) if donate else ()
         extra_slots = tuple(extra_slots)
         if fused:
             def _boundary(params, opt_state, feed):
-                batch = self.apply(feed)
+                with jax.named_scope(phases.FEED):
+                    batch = self.apply(feed)
                 batch.update({k: feed[k] for k in extra_slots})
                 return train_step(params, opt_state, batch)
             jitted = jax.jit(_boundary, donate_argnums=donate_args)
@@ -365,6 +374,9 @@ class ModelFeed:
                     f"slots: {extra_slots}) — is the working-set prefetch "
                     f"stage wired in?") from None
             return feed
+
+        # The enabled tracer that holds this step's phase map, if any.
+        mapped_for = [None]
 
         def step(params, opt_state, env):
             tracer = get_tracer()
@@ -388,7 +400,17 @@ class ModelFeed:
                     # donation is still wanted (params/opt DO alias, and
                     # the feeder accounts batch donation via the fence).
                     warnings.filterwarnings("ignore", message=_DONATE_MSG)
-                new_params, new_opt, metrics = jitted(params, opt_state, feed)
+                if tracer.enabled and mapped_for[0] is not tracer:
+                    # Once per tracer, before the step runs: which compiled
+                    # instruction is in which phase (shares the jit's
+                    # compile, so nothing compiles twice).
+                    mapped_for[0] = tracer
+                    compiled = jitted.lower(params, opt_state, feed).compile()
+                    tracer.annotate(phases.STEP_PHASES,
+                                    phases.phase_map(compiled.as_text()))
+                with tracer.span("train.dispatch"):
+                    new_params, new_opt, metrics = jitted(params, opt_state,
+                                                          feed)
             stats.steps += 1
             # Register the fence BEFORE touching metric values: _record
             # blocks on the step's results, and the feeder may already be
@@ -398,7 +420,8 @@ class ModelFeed:
                 if fence is None and metrics:
                     fence = next(iter(metrics.values()))
                 fence_cb(fence)
-            self._record(metrics)
+            with tracer.span("train.sync"):
+                self._record(metrics)
             return new_params, new_opt, metrics
 
         step.feed_stats = stats

@@ -332,35 +332,20 @@ def run_streaming(args, spec, cfg, key, opt, check_report=None) -> Dict[str, Any
                 feed.update(extras)
             p, o = abstractify((state["params"], state["opt"]))
             cost_args.append((p, o, feed))
-        w0 = tracer.now_ns() if (tracer.enabled and comm is not None) else 0
         t0 = time.perf_counter()
         p, o, m = fused(state["params"], state["opt"], env)
         if hier is not None:
             # Async write-back: hand the updated working set to the PS
             # writer thread; the pull for batch i+2 waits on it, not us.
             hier.complete(env[WS_META], m.pop("ws_rows"), m.pop("ws_accum"))
-        losses.append(float(m["loss"]))  # blocks until the step lands
+        with tracer.span("train.sync"):
+            losses.append(float(m["loss"]))  # blocks until the step lands
         step_seconds.append(time.perf_counter() - t0)
         if not math.isfinite(losses[-1]):
             raise FloatingPointError(
                 f"non-finite loss {losses[-1]} at step {len(losses) - 1}")
         if comm is not None:
             comm.on_step()
-            if tracer.enabled:
-                # The collectives execute inside the fused XLA step, so
-                # their spans cover the step window on dedicated virtual
-                # tracks, annotated with the plan's modeled inter-pod
-                # bytes (exchange = working set + dedup pool).
-                w1 = tracer.now_ns()
-                cp = comm.plan
-                tracer.complete_on(
-                    "comm.exchange", "comm.exchange", w0, w1,
-                    interpod_bytes=(cp.exchange_interpod_bytes
-                                    + cp.dedup_interpod_bytes))
-                tracer.complete_on(
-                    "comm.allreduce", "comm.allreduce", w0, w1,
-                    interpod_bytes=cp.allreduce_interpod_bytes,
-                    codec=cp.codec or "off")
         state = {"params": p, "opt": o}
         if ckpt is not None and len(losses) % args.checkpoint_every == 0:
             ckpt.save_async(start_step + len(losses) - 1, state,

@@ -27,6 +27,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.embedding.table import MultiTable, TableSpec, lookup, lookup_dedup
+from repro.obs import phases
 from repro.models.common import (
     dense as dense_layer,
     he_init,
@@ -391,28 +392,31 @@ def make_sparse_train_step(c: RecsysConfig, dense_optimizer, *,
         }
 
     def train_step(params, opt_state, batch):
-        gids = collect_gids(c, batch)
-        sites = sorted(gids.keys())
-        flat_all = jnp.concatenate([gids[s].reshape(-1) for s in sites])
-        cap = c.dedup_capacity or int(flat_all.shape[0])
-        if mesh is not None and batch_axes is not None and local_dedup_capacity:
-            # two-stage dedup: shrink the globally-sorted pool (§Perf pair 1)
-            from repro.embedding.dedup import dedup_hierarchical
-            unique, inverse, n_unique = dedup_hierarchical(
-                flat_all, capacity=cap, mesh=mesh, axes=batch_axes,
-                local_capacity=local_dedup_capacity)
-        else:
-            unique, inverse, n_unique = dedup(flat_all, capacity=cap)
-        safe = jnp.where(unique == jnp.int32(2**31 - 1), 0, unique)
-        working = jnp.take(params["embed"], safe, axis=0)        # (cap, D)
+        with jax.named_scope(phases.DEDUP):
+            gids = collect_gids(c, batch)
+            sites = sorted(gids.keys())
+            flat_all = jnp.concatenate([gids[s].reshape(-1) for s in sites])
+            cap = c.dedup_capacity or int(flat_all.shape[0])
+            if mesh is not None and batch_axes is not None and local_dedup_capacity:
+                # two-stage dedup: shrink the globally-sorted pool (§Perf pair 1)
+                from repro.embedding.dedup import dedup_hierarchical
+                unique, inverse, n_unique = dedup_hierarchical(
+                    flat_all, capacity=cap, mesh=mesh, axes=batch_axes,
+                    local_capacity=local_dedup_capacity)
+            else:
+                unique, inverse, n_unique = dedup(flat_all, capacity=cap)
+        with jax.named_scope(phases.LOOKUP):
+            safe = jnp.where(unique == jnp.int32(2**31 - 1), 0, unique)
+            working = jnp.take(params["embed"], safe, axis=0)    # (cap, D)
 
         # split inverse back per call site
         inv_by_site = {}
         off = 0
-        for s in sites:
-            n = int(np.prod(gids[s].shape))
-            inv_by_site[s] = inverse[off: off + n].reshape(gids[s].shape)
-            off += n
+        with jax.named_scope(phases.DEDUP):
+            for s in sites:
+                n = int(np.prod(gids[s].shape))
+                inv_by_site[s] = inverse[off: off + n].reshape(gids[s].shape)
+                off += n
 
         dense_params = {k: v for k, v in params.items() if k != "embed"}
 
@@ -426,30 +430,32 @@ def make_sparse_train_step(c: RecsysConfig, dense_optimizer, *,
             logits = forward(p2, c, b2)
             return sigmoid_bce(logits, batch["label"]).mean()
 
-        loss, (gd, gw) = jax.value_and_grad(local_loss, argnums=(0, 1))(
-            dense_params, working)
+        with jax.named_scope(phases.FWD_BWD):
+            loss, (gd, gw) = jax.value_and_grad(local_loss, argnums=(0, 1))(
+                dense_params, working)
 
-        # dense update
-        new_dense, new_dense_state = dense_optimizer.update(
-            dense_params, gd, opt_state["dense"])
+        with jax.named_scope(phases.DENSE_UPDATE):
+            new_dense, new_dense_state = dense_optimizer.update(
+                dense_params, gd, opt_state["dense"])
 
         # Adagrad on working rows only
-        gw = gw.astype(jnp.float32)
-        valid = (unique != jnp.int32(2**31 - 1)).astype(jnp.float32)[:, None]
-        gw = gw * valid
-        gsq = jnp.sum(gw * gw, axis=-1)
-        accum_rows = jnp.take(opt_state["embed_accum"], safe) + gsq
-        scale = embed_lr / (jnp.sqrt(accum_rows) + embed_eps)
-        new_rows = (working.astype(jnp.float32) - scale[:, None] * gw)
-        # mode="drop": FILL ids are out of bounds, so padded slots write
-        # nothing. Scattering via ``safe`` would alias every pad slot onto
-        # row 0 and could clobber row 0's real update (duplicate-index
-        # scatter order is unspecified) — observed as a one-row divergence
-        # from the hierarchical-PS path, which pads host-side and never
-        # pushes pad slots.
-        embed = params["embed"].at[unique].set(
-            new_rows.astype(params["embed"].dtype), mode="drop")
-        accum = opt_state["embed_accum"].at[unique].set(accum_rows, mode="drop")
+        with jax.named_scope(phases.EMBED_UPDATE):
+            gw = gw.astype(jnp.float32)
+            valid = (unique != jnp.int32(2**31 - 1)).astype(jnp.float32)[:, None]
+            gw = gw * valid
+            gsq = jnp.sum(gw * gw, axis=-1)
+            accum_rows = jnp.take(opt_state["embed_accum"], safe) + gsq
+            scale = embed_lr / (jnp.sqrt(accum_rows) + embed_eps)
+            new_rows = (working.astype(jnp.float32) - scale[:, None] * gw)
+            # mode="drop": FILL ids are out of bounds, so padded slots write
+            # nothing. Scattering via ``safe`` would alias every pad slot onto
+            # row 0 and could clobber row 0's real update (duplicate-index
+            # scatter order is unspecified) — observed as a one-row divergence
+            # from the hierarchical-PS path, which pads host-side and never
+            # pushes pad slots.
+            embed = params["embed"].at[unique].set(
+                new_rows.astype(params["embed"].dtype), mode="drop")
+            accum = opt_state["embed_accum"].at[unique].set(accum_rows, mode="drop")
 
         new_params = dict(new_dense)
         new_params["embed"] = embed
@@ -581,44 +587,48 @@ def make_mesh_train_step(c: RecsysConfig, dense_optimizer, *,
         accum_shard = opt_state["embed_accum"]          # (rows/n_dev,)
         shard_rows = int(embed_shard.shape[0])
         dense_params = {k: v for k, v in params.items() if k != "embed"}
-        dev = (jax.lax.axis_index(pod_axis) * inner
-               + jax.lax.axis_index(data_axis))
-        lo = dev * shard_rows                           # first owned row
+        with jax.named_scope(phases.EXCHANGE):
+            dev = (jax.lax.axis_index(pod_axis) * inner
+                   + jax.lax.axis_index(data_axis))
+            lo = dev * shard_rows                       # first owned row
 
-        gids = collect_gids(c, batch)                   # local batch shard
-        sites = sorted(gids.keys())
-        flat_local = jnp.concatenate([gids[s].reshape(-1) for s in sites])
-        n_local = int(flat_local.shape[0])
-        cap = c.dedup_capacity or n_local * n_dev
-        local_cap = local_dedup_capacity or min(cap, n_local)
-        if n_dev == 1:
-            # stage 1 must never overflow when it IS the whole dedup
-            local_cap = min(cap, n_local)
+        with jax.named_scope(phases.DEDUP):
+            gids = collect_gids(c, batch)               # local batch shard
+            sites = sorted(gids.keys())
+            flat_local = jnp.concatenate([gids[s].reshape(-1) for s in sites])
+            n_local = int(flat_local.shape[0])
+            cap = c.dedup_capacity or n_local * n_dev
+            local_cap = local_dedup_capacity or min(cap, n_local)
+            if n_dev == 1:
+                # stage 1 must never overflow when it IS the whole dedup
+                local_cap = min(cap, n_local)
 
-        unique, inverse, n_unique, local_count = dedup_two_stage_local(
-            flat_local, capacity=cap, local_capacity=local_cap,
-            gather_axes=axes)
+            unique, inverse, n_unique, local_count = dedup_two_stage_local(
+                flat_local, capacity=cap, local_capacity=local_cap,
+                gather_axes=axes)
 
         # -------- working-set exchange: each device contributes owned rows
-        local_idx = unique - lo                         # FILL -> huge
-        owned = (local_idx >= 0) & (local_idx < shard_rows)
-        idx = jnp.clip(local_idx, 0, shard_rows - 1)
-        contrib = jnp.where(owned[:, None],
-                            jnp.take(embed_shard, idx, axis=0), 0.0)
-        acc_contrib = jnp.where(owned, jnp.take(accum_shard, idx), 0.0)
-        packed = jnp.concatenate([
-            contrib.astype(jnp.float32).reshape(-1), acc_contrib])
-        red, _ = _reduce(_pad_to_inner(packed))         # fp32, never quantized
-        working = red[:cap * c.embed_dim].reshape(cap, c.embed_dim)
-        accum_rows0 = red[cap * c.embed_dim: cap * c.embed_dim + cap]
+        with jax.named_scope(phases.EXCHANGE):
+            local_idx = unique - lo                     # FILL -> huge
+            owned = (local_idx >= 0) & (local_idx < shard_rows)
+            idx = jnp.clip(local_idx, 0, shard_rows - 1)
+            contrib = jnp.where(owned[:, None],
+                                jnp.take(embed_shard, idx, axis=0), 0.0)
+            acc_contrib = jnp.where(owned, jnp.take(accum_shard, idx), 0.0)
+            packed = jnp.concatenate([
+                contrib.astype(jnp.float32).reshape(-1), acc_contrib])
+            red, _ = _reduce(_pad_to_inner(packed))     # fp32, never quantized
+            working = red[:cap * c.embed_dim].reshape(cap, c.embed_dim)
+            accum_rows0 = red[cap * c.embed_dim: cap * c.embed_dim + cap]
 
         inv_by_site = {}
         off = 0
-        for s in sites:
-            n = int(np.prod(gids[s].shape))
-            inv_by_site[s] = inverse.reshape(-1)[off: off + n].reshape(
-                gids[s].shape)
-            off += n
+        with jax.named_scope(phases.DEDUP):
+            for s in sites:
+                n = int(np.prod(gids[s].shape))
+                inv_by_site[s] = inverse.reshape(-1)[off: off + n].reshape(
+                    gids[s].shape)
+                off += n
 
         def local_loss(dense_p, working_rows):
             rows = {f"_rows_{s}": jnp.take(working_rows, inv_by_site[s],
@@ -629,52 +639,56 @@ def make_mesh_train_step(c: RecsysConfig, dense_optimizer, *,
             logits = forward(dict(dense_p), c, b2)
             return sigmoid_bce(logits, batch["label"]).mean()
 
-        loss, (gd, gw) = jax.value_and_grad(local_loss, argnums=(0, 1))(
-            dense_params, working.astype(c.dtype))
+        with jax.named_scope(phases.FWD_BWD):
+            loss, (gd, gw) = jax.value_and_grad(local_loss, argnums=(0, 1))(
+                dense_params, working.astype(c.dtype))
 
         # -------- gradient reduction (the compressed inter-pod wire)
-        gw = gw.astype(jnp.float32)
-        valid = (unique != FILL).astype(jnp.float32)[:, None]
-        gw = gw * valid
-        # stateless codec: working-set slots alias different rows each step
-        gw_red, _ = _reduce(_pad_to_inner(gw.reshape(-1)), codec=codec)
-        gw = gw_red[:cap * c.embed_dim].reshape(cap, c.embed_dim)
+        with jax.named_scope(phases.GRAD_REDUCE):
+            gw = gw.astype(jnp.float32)
+            valid = (unique != FILL).astype(jnp.float32)[:, None]
+            gw = gw * valid
+            # stateless codec: working-set slots alias different rows each step
+            gw_red, _ = _reduce(_pad_to_inner(gw.reshape(-1)), codec=codec)
+            gw = gw_red[:cap * c.embed_dim].reshape(cap, c.embed_dim)
 
-        gd_leaves, gd_def = jax.tree.flatten(gd)
-        gd_flat = jnp.concatenate(
-            [l.reshape(-1).astype(jnp.float32) for l in gd_leaves])
-        residual = (opt_state["comm_residual"][0]
-                    if codec is not None else None)
-        gd_red, new_residual = _reduce(_pad_to_inner(gd_flat), codec=codec,
-                                       residual=residual)
-        if n_dev > 1:
-            inv_ndev = np.float32(1.0 / n_dev)
-            loss = jax.lax.psum(loss, axes) * inv_ndev
-            gw = gw * inv_ndev
-            gd_red = gd_red * inv_ndev
-            local_count = jax.lax.psum(local_count, axes)
-        parts, off = [], 0
-        for leaf in gd_leaves:
-            n = int(np.prod(leaf.shape))
-            parts.append(gd_red[off: off + n].reshape(leaf.shape)
-                         .astype(leaf.dtype))
-            off += n
-        gd = jax.tree.unflatten(gd_def, parts)
+            gd_leaves, gd_def = jax.tree.flatten(gd)
+            gd_flat = jnp.concatenate(
+                [l.reshape(-1).astype(jnp.float32) for l in gd_leaves])
+            residual = (opt_state["comm_residual"][0]
+                        if codec is not None else None)
+            gd_red, new_residual = _reduce(_pad_to_inner(gd_flat), codec=codec,
+                                           residual=residual)
+            if n_dev > 1:
+                inv_ndev = np.float32(1.0 / n_dev)
+                loss = jax.lax.psum(loss, axes) * inv_ndev
+                gw = gw * inv_ndev
+                gd_red = gd_red * inv_ndev
+                local_count = jax.lax.psum(local_count, axes)
+            parts, off = [], 0
+            for leaf in gd_leaves:
+                n = int(np.prod(leaf.shape))
+                parts.append(gd_red[off: off + n].reshape(leaf.shape)
+                             .astype(leaf.dtype))
+                off += n
+            gd = jax.tree.unflatten(gd_def, parts)
 
         # -------- replicated updates, sharded write-back
-        new_dense, new_dense_state = dense_optimizer.update(
-            dense_params, gd, opt_state["dense"])
+        with jax.named_scope(phases.DENSE_UPDATE):
+            new_dense, new_dense_state = dense_optimizer.update(
+                dense_params, gd, opt_state["dense"])
 
-        gsq = jnp.sum(gw * gw, axis=-1)
-        accum_rows = accum_rows0 + gsq
-        scale = embed_lr / (jnp.sqrt(accum_rows) + embed_eps)
-        new_rows = working - scale[:, None] * gw
-        # scatter only the rows this shard owns; everything else (other
-        # shards' rows AND FILL pad slots) routes out of bounds -> dropped
-        target = jnp.where(owned, local_idx, shard_rows)
-        embed_shard = embed_shard.at[target].set(
-            new_rows.astype(embed_shard.dtype), mode="drop")
-        accum_shard = accum_shard.at[target].set(accum_rows, mode="drop")
+        with jax.named_scope(phases.EMBED_UPDATE):
+            gsq = jnp.sum(gw * gw, axis=-1)
+            accum_rows = accum_rows0 + gsq
+            scale = embed_lr / (jnp.sqrt(accum_rows) + embed_eps)
+            new_rows = working - scale[:, None] * gw
+            # scatter only the rows this shard owns; everything else (other
+            # shards' rows AND FILL pad slots) routes out of bounds -> dropped
+            target = jnp.where(owned, local_idx, shard_rows)
+            embed_shard = embed_shard.at[target].set(
+                new_rows.astype(embed_shard.dtype), mode="drop")
+            accum_shard = accum_shard.at[target].set(accum_rows, mode="drop")
 
         new_params = dict(new_dense)
         new_params["embed"] = embed_shard

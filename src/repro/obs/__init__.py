@@ -7,7 +7,10 @@
   plus derived pipeline-level metrics (overlap, stall attribution,
   bytes-and-seconds rollup);
 * :mod:`repro.obs.validate` — structural trace validation (also a CLI:
-  ``python -m repro.obs.validate trace.json``).
+  ``python -m repro.obs.validate trace.json``);
+* :mod:`repro.obs.phases` — the train step's device phases (its
+  ``jax.named_scope`` names) and the map from compiled instructions to
+  them that an enabled tracer records.
 
 This package intentionally imports nothing from the rest of ``repro`` (no
 jax, no numpy): every pipeline tier can depend on it without layering

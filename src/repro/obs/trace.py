@@ -38,14 +38,22 @@ Typical use::
     get_tracer().counter("io.queue_depth", 2)
     ...
     get_tracer().export("trace.json")
+
+While an enabled tracer is installed (:func:`set_tracer`), it also records
+each garbage collection as a ``gc.collect`` span on a virtual ``gc`` track.
+Timestamps are ``time.perf_counter_ns``; the exported JSON states that
+clock and the tracer's epoch under ``metadata``, next to what the program
+annotated (:meth:`Tracer.annotate`), e.g. the train step's phase map.
 """
 
 from __future__ import annotations
 
+import collections
+import gc
 import json
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Deque, Dict, List, Optional, Tuple
 
 # The single pid all tracks share (one process; tracks are threads).
 PID = 1
@@ -115,6 +123,13 @@ class Tracer:
         # thread ident (int) or "virtual:<name>" (str) -> (tid, track name)
         self._tracks: Dict[Any, Tuple[int, str]] = {}
         self._epoch_ns = time.perf_counter_ns()
+        self._annotations: Dict[str, Any] = {}
+        # Collections end here from whichever thread ran them, without the
+        # lock (one may start while this thread holds it); readers move
+        # them onto the gc track.
+        self._gc_done: Deque[Tuple[int, int, int, int]] = collections.deque()
+        self._gc_t0: Optional[int] = None
+        self._gc_watching = False
 
     # ------------------------------------------------------------ recording
     def span(self, name: str, **args: Any) -> Any:
@@ -163,26 +178,66 @@ class Tracer:
 
         :meth:`complete` reuses the calling thread's track, which is only
         monotonicity-safe when that thread recorded nothing inside the
-        window. Work that happens *inside* another span — e.g. the
-        collective phases of a fused train step, which execute within the
-        step's own ``train.step`` span — would interleave non-monotone
-        B/E pairs on the thread track. A virtual track (one per ``track``
-        name, lazily allocated, keyed separately from thread idents)
-        gives each such series its own monotone timeline in the exported
-        timeline — the ``comm.*`` spans of the mesh train loop live here.
+        window. Work that happens *inside* another span — e.g. a collection
+        that pauses a thread inside its ``train.step`` span — would
+        interleave non-monotone B/E pairs on the thread track. A virtual
+        track (one per ``track`` name, lazily allocated, keyed separately
+        from thread idents) gives each such series its own monotone
+        timeline in the exported trace — the ``gc.collect`` spans live on
+        the ``gc`` track.
         """
         if not self.enabled:
             return
-        a = args or None
         with self._lock:
-            key = f"virtual:{track}"
-            entry = self._tracks.get(key)
-            if entry is None:
-                entry = (len(self._tracks), track)
-                self._tracks[key] = entry
-            tid = entry[0]
-            self._events.append((_B, tid, t0_ns, name, a))
-            self._events.append((_E, tid, t1_ns, name, None))
+            self._complete_on_locked(track, name, t0_ns, t1_ns, args or None)
+
+    def _complete_on_locked(self, track: str, name: str, t0_ns: int,
+                            t1_ns: int, args: Optional[Dict[str, Any]]) -> None:
+        key = f"virtual:{track}"
+        entry = self._tracks.get(key)
+        if entry is None:
+            entry = (len(self._tracks), track)
+            self._tracks[key] = entry
+        tid = entry[0]
+        self._events.append((_B, tid, t0_ns, name, args))
+        self._events.append((_E, tid, t1_ns, name, None))
+
+    def annotate(self, key: str, value: Any) -> None:
+        """Record a fact about the program (not an event) under ``key``,
+        exported under the trace's top-level ``metadata``."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self._annotations[key] = value
+
+    # ------------------------------------------------------------ collector
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            self._gc_t0 = time.perf_counter_ns()
+        elif self._gc_t0 is not None:
+            if self.enabled:
+                self._gc_done.append((self._gc_t0, time.perf_counter_ns(),
+                                      info.get("generation", -1),
+                                      info.get("collected", 0)))
+            self._gc_t0 = None
+
+    def _watch_gc(self) -> None:
+        if self.enabled and not self._gc_watching:
+            gc.callbacks.append(self._on_gc)
+            self._gc_watching = True
+
+    def _unwatch_gc(self) -> None:
+        if self._gc_watching:
+            gc.callbacks.remove(self._on_gc)
+            self._gc_watching = False
+        self._gc_t0 = None
+
+    def _drain_gc_locked(self) -> None:
+        while self._gc_done:
+            t0, t1, generation, collected = self._gc_done.popleft()
+            self._complete_on_locked(
+                "gc", "gc.collect", t0, t1,
+                {"generation": generation, "collected": collected})
 
     def now_ns(self) -> int:
         """Monotonic stamp compatible with :meth:`complete` (cheap enough
@@ -207,15 +262,29 @@ class Tracer:
     @property
     def n_events(self) -> int:
         with self._lock:
+            self._drain_gc_locked()
             return len(self._events)
 
     def track_names(self) -> Dict[int, str]:
         """tid -> thread name for every track that recorded an event."""
         with self._lock:
+            self._drain_gc_locked()
             return {tid: name for tid, name in self._tracks.values()}
 
-    def clear(self) -> None:
+    @property
+    def metadata(self) -> Dict[str, Any]:
+        """The clock (``perf_counter_ns`` and this tracer's epoch) and what
+        the program annotated: the export's top-level ``metadata``."""
         with self._lock:
+            return {**self._annotations, "clock": "perf_counter_ns",
+                    "epoch_ns": self._epoch_ns}
+
+    def clear(self) -> None:
+        """Drop every event and track, and stop recording collections;
+        annotations (facts about the program) stay."""
+        self._unwatch_gc()
+        with self._lock:
+            self._gc_done.clear()
             self._events.clear()
             self._tracks.clear()
             self._epoch_ns = time.perf_counter_ns()
@@ -224,11 +293,14 @@ class Tracer:
     def to_dict(self) -> Dict[str, Any]:
         """The trace as a Chrome trace-event object (``traceEvents`` list).
 
-        Timestamps are microseconds relative to the tracer's epoch. Spans
+        Timestamps are microseconds relative to the tracer's epoch
+        (``metadata["epoch_ns"]`` on the ``perf_counter_ns`` clock). Spans
         left open by a thread that died mid-span are end-capped at the
         trace's last timestamp so every B has a matching E.
         """
+        metadata = self.metadata
         with self._lock:
+            self._drain_gc_locked()
             events = list(self._events)
             tracks = dict(self._tracks)
         out: List[Dict[str, Any]] = [{
@@ -260,7 +332,8 @@ class Tracer:
                 out.append({"ph": _E, "name": name, "pid": PID, "tid": tid,
                             "ts": (last_ts[tid] - self._epoch_ns) / 1e3,
                             "args": {"capped": True}})
-        return {"traceEvents": out, "displayTimeUnit": "ms"}
+        return {"traceEvents": out, "displayTimeUnit": "ms",
+                "metadata": metadata}
 
     def export(self, path: str) -> Dict[str, Any]:
         """Write the Chrome trace-event JSON to ``path`` (returns the dict).
@@ -286,9 +359,13 @@ def get_tracer() -> Tracer:
 
 def set_tracer(tracer: Tracer) -> Tracer:
     """Install ``tracer`` process-wide; returns the previous tracer so
-    callers (tests, drivers) can restore it."""
+    callers (tests, drivers) can restore it. An enabled tracer records
+    collections from here until it is replaced or cleared."""
     global _tracer
     prev = _tracer
+    if prev is not tracer:
+        prev._unwatch_gc()
+        tracer._watch_gc()
     _tracer = tracer
     return prev
 

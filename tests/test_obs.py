@@ -128,28 +128,140 @@ def test_complete_records_retroactive_span():
 
 def test_complete_on_virtual_track():
     """complete_on places retroactive spans on a named virtual track (the
-    comm.* collectives tier) without violating the per-track timestamp
-    monotonicity the validator enforces."""
+    ``gc`` track of collector pauses) without violating the per-track
+    timestamp monotonicity the validator enforces."""
     t = Tracer(enabled=True)
     with t.span("train.step"):
         pass
     t0 = t.now_ns()
-    t.complete_on("comm.allreduce", "comm.allreduce",
-                  t0 - 4_000_000, t0 - 2_000_000, interpod_bytes=1234)
-    t.complete_on("comm.allreduce", "comm.allreduce",
-                  t0 - 2_000_000, t0, interpod_bytes=1234)
+    t.complete_on("gc", "gc.collect",
+                  t0 - 4_000_000, t0 - 2_000_000, generation=2)
+    t.complete_on("gc", "gc.collect",
+                  t0 - 2_000_000, t0, generation=2)
     d = t.to_dict()
     names = set(t.track_names().values())
-    assert "comm.allreduce" in names
-    ivals = span_intervals(d, "comm.")
+    assert "gc" in names
+    ivals = span_intervals(d, "gc.")
     assert len(ivals) == 2
     assert ivals[0][1] - ivals[0][0] == pytest.approx(2_000.0)  # us
     args = [e["args"] for e in d["traceEvents"]
-            if e.get("ph") == "B" and e["name"] == "comm.allreduce"]
-    assert args and all(a["interpod_bytes"] == 1234 for a in args)
+            if e.get("ph") == "B" and e["name"] == "gc.collect"]
+    assert args and all(a["generation"] == 2 for a in args)
     # retroactive spans starting before the last wall-clock event on
     # ANOTHER track must not trip the validator's monotonicity check
     validate_trace(d)
+
+
+def test_collections_recorded_on_gc_track_while_installed():
+    """An installed, enabled tracer records each collection as a
+    ``gc.collect`` span on the ``gc`` track; replacing it removes its
+    callback, and a disabled tracer installs none."""
+    import gc
+
+    t = Tracer(enabled=True)
+    n_callbacks = len(gc.callbacks)
+    prev = set_tracer(t)
+    try:
+        assert len(gc.callbacks) == n_callbacks + 1
+        with t.span("train.step"):
+            gc.collect()
+        d = t.to_dict()
+    finally:
+        set_tracer(prev)
+    assert len(gc.callbacks) == n_callbacks
+    validate_trace(d)
+    gc_tid = {tid for tid, name in t.track_names().items() if name == "gc"}
+    spans = [e for e in d["traceEvents"]
+             if e.get("ph") == "B" and e["name"] == "gc.collect"]
+    assert spans and {e["tid"] for e in spans} == gc_tid
+    assert any(e["args"]["generation"] == 2 for e in spans)
+    assert all(e["args"]["collected"] >= 0 for e in spans)
+    step = span_intervals(d, "train.step")[0]
+    assert any(step[0] <= e["ts"] <= step[1] for e in spans)
+    n = len(spans)
+    gc.collect()  # no longer installed: not recorded
+    assert len(span_intervals(t.to_dict(), "gc.collect")) == n
+
+    off = Tracer(enabled=False)
+    prev = set_tracer(off)
+    try:
+        assert len(gc.callbacks) == n_callbacks
+    finally:
+        set_tracer(prev)
+
+
+def test_gc_spans_from_many_threads_lose_nothing():
+    """Collections run on whichever thread allocates, also while that
+    thread holds the tracer's lock: every one is recorded once, with no
+    deadlock, and the trace stays well formed."""
+    import gc
+    import sys
+
+    t = Tracer(enabled=True)
+    stops = []
+
+    def count(phase, info):
+        if phase == "stop":
+            stops.append(info["generation"])
+
+    def churn(i):
+        for j in range(300):
+            with t.span("work", i=i):
+                a, b = [], []
+                a.append(b)
+                b.append(a)  # a cycle: only the collector frees it
+            t.instant("mark")
+
+    interval = sys.getswitchinterval()
+    prev = set_tracer(t)
+    gc.callbacks.append(count)
+    threshold = gc.get_threshold()
+    gc.set_threshold(50)
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=churn, args=(i,), name=f"churn-{i}")
+                   for i in range(16)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+        assert not any(th.is_alive() for th in threads)
+    finally:
+        sys.setswitchinterval(interval)
+        gc.set_threshold(*threshold)
+        gc.callbacks.remove(count)
+        set_tracer(prev)
+    d = t.to_dict()
+    validate_trace(d)
+    assert len(span_intervals(d, "gc.collect")) == len(stops) > 0
+
+
+def test_clear_removes_the_gc_callback(traced):
+    import gc
+
+    assert traced._on_gc in gc.callbacks
+    traced.clear()
+    assert traced._on_gc not in gc.callbacks
+    gc.collect()
+    assert traced.n_events == 0
+
+
+def test_export_states_its_clock_and_annotations():
+    t = Tracer(enabled=True)
+    t0 = t.now_ns()
+    t.complete("stall", t0, t0 + 2_000_000)
+    t.annotate("step.phases", {"fusion.1": "dedup"})
+    md = t.to_dict()["metadata"]
+    assert md["clock"] == "perf_counter_ns"
+    assert md["step.phases"] == {"fusion.1": "dedup"}
+    # a span's exported microseconds, on the stated epoch, give back its
+    # perf_counter_ns stamps: a profile taken alongside aligns on them
+    start, end, _, _ = span_intervals(t.to_dict(), "stall")[0]
+    assert round(start * 1e3) + md["epoch_ns"] == t0
+    assert round(end * 1e3) + md["epoch_ns"] == t0 + 2_000_000
+    off = Tracer(enabled=False)
+    off.annotate("k", 1)
+    assert "k" not in off.metadata
 
 
 def test_tracks_named_after_threads():

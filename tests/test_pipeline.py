@@ -240,26 +240,28 @@ def test_train_feed_absent_without_feed_stats():
 
 # ------------------------------------------- derived accounting invariants
 def test_accounting_identity_exact_arithmetic():
-    """overhead/overlap are exact complements of wall - busy: overhead is
-    never negative, at most one of the two is nonzero, and
-    wall == busy + overhead - overlap holds to float precision."""
+    """Overhead is the wall time left once busy time is counted with the
+    measured overlap taken out once: never negative, the overlap never
+    exceeds the smaller stage, and wall == busy - overlap + overhead holds
+    to float precision. A run can show both (start-up overhead around a
+    pipelined middle), which wall - busy alone could not."""
     from repro.core.pipeline import PipelineStats
 
     cases = [
-        # (fe, train, drain, wall)
-        (1.0, 2.0, 0.0, 3.5),   # serial-ish: overhead 0.5
-        (1.0, 2.0, 0.0, 2.4),   # pipelined: overlap 0.6
-        (1.0, 2.0, 0.5, 3.5),   # exact: overhead == overlap == 0
-        (0.0, 0.0, 0.0, 0.0),   # empty run
-        (0.3, 5.0, 0.0, 5.05),  # train-bound
+        # (fe, train, drain, overlap, wall)
+        (1.0, 2.0, 0.0, 0.0, 3.5),   # serial-ish: overhead 0.5
+        (1.0, 2.0, 0.0, 0.6, 2.4),   # pipelined: overlap 0.6
+        (1.0, 2.0, 0.5, 0.0, 3.5),   # exact: overhead == overlap == 0
+        (0.0, 0.0, 0.0, 0.0, 0.0),   # empty run
+        (0.3, 5.0, 0.0, 0.3, 5.05),  # train-bound: FE hidden, 0.05 start-up
     ]
-    for fe, train, drain, wall in cases:
+    for fe, train, drain, overlap, wall in cases:
         s = PipelineStats(fe_seconds=fe, train_seconds=train,
-                          drain_seconds=drain, wall_seconds=wall)
+                          drain_seconds=drain, overlap_seconds=overlap,
+                          wall_seconds=wall)
         assert s.busy_seconds == fe + train + drain
         assert s.overhead_seconds >= 0.0
-        assert s.overlap_seconds >= 0.0
-        assert s.overhead_seconds * s.overlap_seconds == 0.0
+        assert 0.0 <= s.overlap_seconds <= min(fe, train)
         assert abs(s.wall_seconds
                    - (s.busy_seconds + s.overhead_seconds
                       - s.overlap_seconds)) < 1e-12
@@ -268,20 +270,61 @@ def test_accounting_identity_exact_arithmetic():
                                   + s.adapt_seconds + s.drain_seconds
                                   + s.overhead_seconds + 1e-12)
         assert 0.0 <= s.overlap_fraction <= 1.0
+    s = PipelineStats(fe_seconds=0.3, train_seconds=5.0, overlap_seconds=0.3,
+                      wall_seconds=5.05)
+    assert abs(s.overhead_seconds - 0.05) < 1e-12
+    assert s.overlap_fraction == 1.0
 
 
 def test_overlap_fraction_bounds_and_degenerate_cases():
     from repro.core.pipeline import PipelineStats
 
     # full overlap: the shorter stage entirely hidden
-    s = PipelineStats(fe_seconds=1.0, train_seconds=3.0, wall_seconds=3.0)
+    s = PipelineStats(fe_seconds=1.0, train_seconds=3.0, overlap_seconds=1.0,
+                      wall_seconds=3.0)
     assert s.overlap_fraction == 1.0
     # no train stage at all: fraction defined as 0, not a ZeroDivision
     s = PipelineStats(fe_seconds=1.0, train_seconds=0.0, wall_seconds=1.0)
     assert s.overlap_fraction == 0.0
     # overlap can exceed min(fe, train) only through float noise: clamped
-    s = PipelineStats(fe_seconds=0.5, train_seconds=10.0, wall_seconds=9.0)
+    s = PipelineStats(fe_seconds=0.5, train_seconds=10.0,
+                      overlap_seconds=0.5 + 1e-12, wall_seconds=10.0)
     assert s.overlap_fraction == 1.0
+
+
+def test_overlap_measured_from_stage_intervals_despite_slow_start():
+    """The overlap comes from the FE and train stages' own busy intervals:
+    a batch source that is slow to start (time neither stage is busy) adds
+    to overhead, and does not hide the overlap behind it."""
+    import time
+
+    layers = compile_layers(build_schedule(build_fe_graph()))
+
+    def slow_source():
+        time.sleep(0.3)
+        yield from (dict(b) for b in _batches(4))
+
+    def slow_train(state, env):
+        time.sleep(0.03)
+        return state
+
+    pipe = PipelinedRunner(layers, slow_train, prefetch=2)
+    pipe.run({}, slow_source())
+    s = pipe.stats
+    assert s.busy_seconds < s.wall_seconds  # wall - busy would read 0
+    assert 0.0 < s.overlap_seconds <= min(s.fe_seconds, s.train_seconds)
+    assert s.overhead_seconds >= 0.3
+    assert abs(s.wall_seconds - (s.busy_seconds - s.overlap_seconds
+                                 + s.overhead_seconds)) < 1e-9
+
+
+def test_shared_seconds_intersects_interval_lists():
+    from repro.core.pipeline import _shared_seconds
+
+    assert _shared_seconds([0.0, 1.0, 2.0, 3.0], [0.5, 2.5]) == 1.0
+    assert _shared_seconds([0.0, 1.0], [1.0, 2.0]) == 0.0
+    assert _shared_seconds([], [0.0, 1.0]) == 0.0
+    assert _shared_seconds([0.0, 4.0], [1.0, 2.0, 3.0, 5.0]) == 2.0
 
 
 def test_accounting_invariant_real_pipelined_run():

@@ -104,15 +104,22 @@ def test_mempool_alloc_kernel_compiles(one_chip, dlrm_plan, n):
 
 
 # ------------------------------------------------------- one-chip full step
-def test_dcn_v2_published_step_fits_one_chip(one_chip, dlrm_plan):
+@pytest.fixture(scope="module")
+def one_chip_step(one_chip, dlrm_plan):
+    """The fused, donated one-chip step at DCN-v2's published widths,
+    compiled for one v5e: its config and compiled program."""
     from repro.models import recsys as R
 
     mf, step, init_opt = _dcn_v2_step(dlrm_plan, R.make_sparse_train_step)
-    cfg = mf.config
-    params, opt = _state_shapes(cfg, init_opt)
+    params, opt = _state_shapes(mf.config, init_opt)
     compiled = step.jitted.lower(
         _abstract(params, one_chip), _abstract(opt, one_chip),
         _feed_shapes(dlrm_plan, mf, one_chip)).compile()
+    return mf.config, compiled
+
+
+def test_dcn_v2_published_step_fits_one_chip(one_chip_step):
+    cfg, compiled = one_chip_step
     mem = compiled.memory_analysis()
     table = cfg.padded_rows * cfg.embed_dim * 4
     accum = cfg.padded_rows * 4
@@ -121,8 +128,26 @@ def test_dcn_v2_published_step_fits_one_chip(one_chip, dlrm_plan):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
 
 
+def _assert_every_instruction_has_a_phase(compiled, want):
+    from repro.obs import phases
+
+    text = compiled.as_text()
+    assert phases.unmapped_work(text) == []
+    assert set(phases.phase_map(text).values()) == set(want)
+
+
+def test_dcn_v2_published_step_names_its_phases(one_chip_step):
+    """Every top-level instruction that runs on the chip, as the TPU's
+    compiler leaves the program, is in one of the step's phases."""
+    _assert_every_instruction_has_a_phase(one_chip_step[1], (
+        "feed", "dedup", "lookup", "fwd_bwd", "dense_update", "embed_update"))
+
+
 # ------------------------------------------------------------ 2x2 mesh step
-def test_dcn_v2_mesh_step_splits_table_over_four_chips(topo, dlrm_plan):
+@pytest.fixture(scope="module")
+def mesh_step(topo, dlrm_plan):
+    """The 2x2 mesh step at DCN-v2's published widths, compiled for four
+    v5e chips: its config and compiled program."""
     import jax
     from jax.sharding import Mesh
 
@@ -142,6 +167,19 @@ def test_dcn_v2_mesh_step_splits_table_over_four_chips(topo, dlrm_plan):
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
         (params, opt), (p_sh, o_sh))
     compiled = step.jitted.lower(*args, _feed_shapes(dlrm_plan, mf)).compile()
+    return cfg, compiled
+
+
+def test_dcn_v2_mesh_step_splits_table_over_four_chips(mesh_step):
+    cfg, compiled = mesh_step
     per_device = compiled.memory_analysis().argument_size_in_bytes
     table = cfg.padded_rows * (cfg.embed_dim + 1) * 4   # rows + accumulators
     assert per_device == pytest.approx(table / 4, rel=0.02)
+
+
+def test_dcn_v2_mesh_step_names_its_phases(mesh_step):
+    """The same on the 2x2 mesh, whose step exchanges the working set and
+    reduces its gradients across chips."""
+    _assert_every_instruction_has_a_phase(mesh_step[1], (
+        "feed", "dedup", "exchange", "fwd_bwd", "grad_reduce",
+        "dense_update", "embed_update"))
