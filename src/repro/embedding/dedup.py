@@ -147,10 +147,14 @@ def dedup_two_stage_local(
     set, so this is the per-device body: local unique (stage 1, bounds the
     pooled sort to ``n_devices x local_capacity`` ids) -> all-gather of the
     FILL-padded local uniques over ``gather_axes`` -> global unique of the
-    pool (replicated compute, stage 2). The local inverse is recovered by
-    ``searchsorted`` against the sorted global unique array — identical to
-    ``jnp.unique``'s inverse (position in the sorted uniques), so on a 1x1
-    mesh the result is bitwise :func:`dedup`.
+    pool (replicated compute, stage 2). The local inverse is composed from
+    the two sorts' own inverses: stage 1 maps each id to its ``local_u``
+    slot, stage 2 maps each pool slot to its ``unique`` slot, and this
+    device's ``local_u`` fills pool slots ``[dev * local_capacity, (dev + 1)
+    * local_capacity)``, ``dev`` being its row-major position over
+    ``gather_axes`` (the tiled all-gather's order). Each id's slot is its
+    position in the sorted global uniques, exactly :func:`jnp.unique`'s
+    inverse, so on a 1x1 mesh the result is bitwise :func:`dedup`.
 
     Returns ``(unique, inverse, count, local_count)`` — ``unique``/``count``
     replicated, ``inverse`` for this device's ``local_ids``, ``local_count``
@@ -158,14 +162,24 @@ def dedup_two_stage_local(
     stats report). ``local_capacity`` must bound this shard's true unique
     count or overflow drops the largest local ids (callers size it with
     :func:`repro.fe.modelfeed.dedup_capacity_hint` on the per-device rows).
+
+    Overflow: an id dropped by either stage gets an inverse ``>= capacity``
+    (out of range, so lookups fill and gradient scatters drop it); every
+    in-range inverse points at the id's own slot. A stage-1 drop is filled
+    with ``capacity`` itself, never read from another device's slice of the
+    pool's inverse.
     """
     flat = local_ids.reshape(-1).astype(jnp.int32)
-    local_u = jnp.unique(flat, size=local_capacity, fill_value=FILL)
+    local_u, local_inv = jnp.unique(flat, return_inverse=True,
+                                    size=local_capacity, fill_value=FILL)
     pool = jax.lax.all_gather(local_u, gather_axes, axis=0, tiled=True)
-    unique = jnp.unique(pool, size=capacity, fill_value=FILL)
-    # every local id is present in `unique` (sorted), so searchsorted is
-    # exactly jnp.unique's inverse for this device's slice of the batch
-    inverse = jnp.searchsorted(unique, flat).astype(jnp.int32)
+    unique, pool_inv = jnp.unique(pool, return_inverse=True, size=capacity,
+                                  fill_value=FILL)
+    dev = jax.lax.axis_index(gather_axes)
+    own_inv = jax.lax.dynamic_slice_in_dim(
+        pool_inv, dev * local_capacity, local_capacity)
+    inverse = jnp.take(own_inv, local_inv, mode="fill",
+                       fill_value=capacity).astype(jnp.int32)
     count = jnp.sum(unique != FILL).astype(jnp.int32)
     local_count = jnp.sum(local_u != FILL).astype(jnp.int32)
     return unique, inverse.reshape(local_ids.shape), count, local_count

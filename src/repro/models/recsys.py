@@ -501,7 +501,9 @@ def make_mesh_train_step(c: RecsysConfig, dense_optimizer, *,
 
     1. **two-stage dedup** — local ``jnp.unique`` bounds the pooled sort to
        ``n_devices x local_capacity`` ids, then a global unique of the
-       all-gathered pool (:func:`repro.embedding.dedup.dedup_two_stage_local`);
+       all-gathered pool; each id's working-set slot composes the two
+       sorts' inverses, with no search over the global uniques
+       (:func:`repro.embedding.dedup.dedup_two_stage_local`);
     2. **working-set exchange** — each device contributes the unique rows +
        accumulators it owns (out-of-shard slots zeroed), one fp32
        hierarchical reduction replicates the working set everywhere;

@@ -224,8 +224,8 @@ for trial in range(6):
     for d in range(8):
         assert (u[d] == flat_u).all(), (trial, d)
         assert int(cnt[d]) == int(flat_cnt)
-        # inverse reconstructs this device's ids (real and FILL alike:
-        # FILL sorts last so searchsorted points at a FILL slot or cnt)
+        # inverse reconstructs this device's ids (a FILL input sorts last,
+        # so its inverse is cnt: a FILL slot, or out of range when full)
         real = ids[d] != int(FILL)
         assert (u[d][inv[d][real]] == ids[d][real]).all(), (trial, d)
         assert int(lcnt[d]) == len(np.unique(ids[d][real]))
@@ -272,6 +272,83 @@ assert int(cnt[0]) == len(kept) <= 8 * LC
 assert np.isin(kept, true_u).all()
 assert (lcnt == LC).all()  # every device overflowed stage 1
 print("LOCAL OVERFLOW OK")
+""")
+
+
+@pytest.mark.parametrize("pods,inner", [(2, 2), (2, 4)])
+def test_two_stage_dedup_inverse_matches_searchsorted(pods, inner):
+    """The inverse composed from the two sorts' own inverses is bitwise the
+    older form that searched every id in the sorted global uniques (kept
+    here as the oracle), on Zipf-skewed ids whose hot ids repeat on every
+    device. Under stage-1 overflow every inverse is out of range
+    (>= capacity) or points at its own id, and no dropped id reads another
+    device's slice of the pool."""
+    run_sub(f"""
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+from repro.embedding.dedup import FILL, MAX_ID, dedup_two_stage_local
+from repro.launch.mesh import make_train_mesh
+
+PODS, INNER = {pods}, {inner}
+N_DEV = PODS * INNER
+mesh = make_train_mesh(PODS, INNER)
+N_LOCAL = 384
+
+def searchsorted_oracle(local_ids, *, capacity, local_capacity, gather_axes):
+    flat = local_ids.reshape(-1).astype(jnp.int32)
+    local_u = jnp.unique(flat, size=local_capacity, fill_value=FILL)
+    pool = jax.lax.all_gather(local_u, gather_axes, axis=0, tiled=True)
+    unique = jnp.unique(pool, size=capacity, fill_value=FILL)
+    inverse = jnp.searchsorted(unique, flat).astype(jnp.int32)
+    count = jnp.sum(unique != FILL).astype(jnp.int32)
+    local_count = jnp.sum(local_u != FILL).astype(jnp.int32)
+    return unique, inverse.reshape(local_ids.shape), count, local_count
+
+def build(fn, cap, local_cap):
+    def body(ids):
+        out = fn(ids[0], capacity=cap, local_capacity=local_cap,
+                 gather_axes=("pod", "data"))
+        return tuple(x[None] for x in out)
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=P(("pod", "data")),
+        out_specs=(P(("pod", "data")),) * 4, check_vma=False))
+
+def zipf_batch(rng, vocab):
+    # one popularity for every device, so hot ids repeat across devices
+    return ((rng.zipf(1.05, size=(N_DEV, N_LOCAL)) - 1) % vocab).astype(np.int32)
+
+rng = np.random.default_rng(11)
+for trial, vocab in enumerate((4096, 50_000, 4096)):
+    ids = zipf_batch(rng, vocab)
+    if trial == 1:   # FILL padding mixed into the input
+        ids[rng.random(ids.shape) < 0.1] = int(FILL)
+    if trial == 2:   # ids hugging the top of the id space
+        ids = MAX_ID - 1 - ids
+    local_cap = max(len(np.unique(r)) for r in ids)   # tight, no overflow
+    cap = N_DEV * local_cap
+    new, old = ([np.asarray(x) for x in build(fn, cap, local_cap)(jnp.asarray(ids))]
+                for fn in (dedup_two_stage_local, searchsorted_oracle))
+    for name, a, b in zip(("unique", "inverse", "count", "local_count"), new, old):
+        assert a.dtype == b.dtype and a.shape == b.shape, (trial, name)
+        assert (a == b).all(), (trial, name)
+    assert new[2][0] < new[3].sum()   # some ids repeat across devices
+print("BITWISE OK")
+
+# stage-1 overflow: each device keeps its LC smallest ids; a dropped id's
+# inverse is out of range, a kept id's inverse points at that id
+LC, CAP = 16, 512
+ids = zipf_batch(rng, 4096)
+u, inv, cnt, lcnt = (np.asarray(x) for x in build(
+    dedup_two_stage_local, CAP, LC)(jnp.asarray(ids)))
+assert (lcnt == LC).all()
+for d in range(N_DEV):
+    kept = np.unique(ids[d])[:LC]
+    dropped = ~np.isin(ids[d], kept)
+    assert dropped.any()
+    assert (inv[d][dropped] >= CAP).all(), d
+    assert (inv[d][~dropped] < CAP).all(), d
+    assert (u[d][inv[d][~dropped]] == ids[d][~dropped]).all(), d
+print("STAGE-1 OVERFLOW OK")
 """)
 
 

@@ -183,3 +183,18 @@ def test_dcn_v2_mesh_step_names_its_phases(mesh_step):
     _assert_every_instruction_has_a_phase(mesh_step[1], (
         "feed", "dedup", "exchange", "fwd_bwd", "grad_reduce",
         "dense_update", "embed_update"))
+
+
+def test_dcn_v2_mesh_step_dedup_runs_no_loop(mesh_step):
+    """The mesh step's dedup composes the two sorts' inverses: no loop (a
+    binary search over the global uniques was one) is left in its phase."""
+    from repro.obs import phases
+
+    text = mesh_step[1].as_text()
+    dedup_ops = {name for name, phase in phases.phase_map(text).items()
+                 if phase == phases.DEDUP}
+    assert dedup_ops
+    loops = [name for name, (opcode, _, _)
+             in phases.entry_instructions(text).items()
+             if opcode == "while" and name in dedup_ops]
+    assert loops == []
